@@ -14,14 +14,18 @@ compute hot-spot of SART's decode phase (the paper's serving substrate):
   * Block tables and context lengths are scalar-prefetched
     (``PrefetchScalarGridSpec``) so the page index_map can consume them —
     the TPU analogue of the CUDA kernel's pointer chasing.
-  * MXU alignment: page_size and head_dim are multiples of 128 in production
-    configs; q is laid out ``[batch, q_heads, head_dim]`` with the GQA group
-    as the sublane dimension.
+  * Layout: the kernel sees q and out as ``[batch, kv_heads, group,
+    head_dim]``, one block per (batch, kv_head) GQA group. The group is then
+    the whole second-last block dim, which Mosaic accepts at any group size;
+    a ``(1, group, head_dim)`` block over ``[batch, q_heads, head_dim]`` is
+    refused unless the group is a multiple of 8 (qwen2-0.5b's is 7). The
+    wrapper keeps the caller's ``[batch, q_heads, head_dim]`` contract.
 
 Validated in ``interpret=True`` mode on CPU against ``ref.py``.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -46,15 +50,15 @@ def paged_attention_grid(
     """Launch geometry for :func:`paged_attention_decode`.
 
     Scalar-prefetch operands: ``bt`` — [batch, pages_per_seq] int32 block
-    tables, ``ln`` — [batch] int32 context lengths. The q operand is the
-    caller's [batch, kv_heads·group, head_dim] layout; its block picks one
-    (batch, kv_head) GQA group.
+    tables, ``ln`` — [batch] int32 context lengths. q and out are
+    [batch, kv_heads, group, head_dim]; a block is one (batch, kv_head) GQA
+    group.
     """
     assert q_heads % kv_heads == 0, (q_heads, kv_heads)
     group = q_heads // kv_heads
 
     def q_index(b, h, i, bt, ln):
-        return (b, h, 0)
+        return (b, h, 0, 0)
 
     def kv_index(b, h, i, bt, ln):
         # sentinel block-table entries (the engine pads tables with
@@ -64,8 +68,8 @@ def paged_attention_grid(
         # HBM out of bounds on TPU
         return (h, jnp.minimum(bt[b, i], num_pages - 1), 0, 0)
 
-    q_map = BlockMapping("q", (batch, kv_heads * group, head_dim),
-                         (1, group, head_dim), q_index)
+    q_map = BlockMapping("q", (batch, kv_heads, group, head_dim),
+                         (1, 1, group, head_dim), q_index)
     kv_shape = (kv_heads, num_pages, page_size, head_dim)
     kv_block = (1, 1, page_size, head_dim)
     return KernelGrid(
@@ -76,10 +80,7 @@ def paged_attention_grid(
             BlockMapping("k_pages", kv_shape, kv_block, kv_index),
             BlockMapping("v_pages", kv_shape, kv_block, kv_index),
         ),
-        out_mappings=(
-            BlockMapping("out", (batch, q_heads, head_dim),
-                         (1, group, head_dim), q_index),
-        ),
+        out_mappings=(dataclasses.replace(q_map, name="out"),),
         num_scalar_prefetch=2,
     )
 
@@ -89,11 +90,11 @@ def _decode_kernel(
     block_tables_ref,    # [B, pages_per_seq] int32
     lengths_ref,         # [B] int32
     # inputs
-    q_ref,               # [1, group, head_dim]
+    q_ref,               # [1, 1, group, head_dim]
     k_ref,               # [1, 1, page_size, head_dim]
     v_ref,               # [1, 1, page_size, head_dim]
     # outputs
-    out_ref,             # [1, group, head_dim]
+    out_ref,             # [1, 1, group, head_dim]
     # scratch
     m_ref,               # [group, 1] f32
     l_ref,               # [group, 1] f32
@@ -117,7 +118,7 @@ def _decode_kernel(
 
     @pl.when(start < length)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale          # [G, hd]
+        q = q_ref[0, 0].astype(jnp.float32) * scale       # [G, hd]
         k = k_ref[0, 0].astype(jnp.float32)                # [P, hd]
         v = v_ref[0, 0].astype(jnp.float32)                # [P, hd]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [G, P]
@@ -138,7 +139,7 @@ def _decode_kernel(
     @pl.when(page_idx == num_pages - 1)
     def _finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        out_ref[0] = (acc_ref[...] / denom).astype(out_ref.dtype)
+        out_ref[0, 0] = (acc_ref[...] / denom).astype(out_ref.dtype)
 
 
 def paged_attention_decode(
@@ -176,12 +177,10 @@ def paged_attention_decode(
         functools.partial(_decode_kernel, page_size=page_size, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (batch, q_heads, head_dim), q.dtype),
+            kg.out_mappings[0].array_shape, q.dtype),
         interpret=interpret,
     )
-    # q reshaped so that (kv_head, group) is explicit for the BlockSpec
-    q4 = q.reshape(batch, kv_heads, group, head_dim)
     out = kernel(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-                 q4.reshape(batch, kv_heads * group, head_dim), k_pages,
+                 q.reshape(batch, kv_heads, group, head_dim), k_pages,
                  v_pages)
-    return out
+    return out.reshape(batch, q_heads, head_dim)

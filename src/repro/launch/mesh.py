@@ -6,23 +6,13 @@ state (jax locks the device count on first backend init).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; 0.4.x builds the same
-    # (fully "auto") mesh without the kwarg
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on pinned jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def make_mesh_compat(axis_shapes, axis_names):
-    """``jax.make_mesh`` across jax versions.
-
-    On jax >= 0.5 every axis is pinned to ``AxisType.Auto`` (the semantics
-    all our pjit code assumes); on jax 0.4.x — where ``axis_types`` does not
-    exist and Auto is the only behaviour — the kwarg is simply omitted.
-    """
-    if AxisType is None:
-        return jax.make_mesh(axis_shapes, axis_names)
+def make_auto_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis pinned to ``AxisType.Auto``, the
+    semantics all the pjit code here assumes (``jax.make_mesh`` defaults
+    to ``Explicit``)."""
     return jax.make_mesh(axis_shapes, axis_names,
                          axis_types=(AxisType.Auto,) * len(axis_names))
 
@@ -31,7 +21,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 # v5e hardware constants for the roofline analysis (per chip)

@@ -1,18 +1,44 @@
 """Serving launcher: SART (or a baseline policy) on the live engine.
 
     PYTHONPATH=src python -m repro.launch.serve --ckpt checkpoints/reasoner \
-        --policy sart --n 8 --requests 16 --rate 0.2
+        --policy sart --n 8 --requests 16 --rate-gap 8
+    PYTHONPATH=src python -m repro.launch.serve --model qwen2-0.5b \
+        --prm head --requests 6
 
 Runs the trained tiny reasoner behind the Algorithm-1 scheduler with the
 requested policy, reports accuracy and step-latency percentiles. With no
 checkpoint, falls back to an untrained model (scheduling behaviour only).
+``--model`` serves a registry config (``repro.configs``) at its published
+widths in bf16, with seeded random weights and a seeded PRM head.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import os
-from typing import Optional, Tuple
+from pathlib import Path
+from typing import Optional
+
+# a fixed path, so that a later run of this checkout finds what an earlier
+# one compiled
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+# KV pages of the serving engine: 0.8 GB of bf16 K+V for qwen2-0.5b. The
+# step program's temporaries are ~3.3x the pool, so 16,384 pages would
+# not fit a 16 GB v5e chip
+NUM_PAGES = 4096
+
+
+def setup_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first compile.
+
+    The cache lives in ``$JAX_COMPILATION_CACHE_DIR`` when that is set,
+    otherwise in ``<repo>/.jax_cache``. Returns the directory."""
+    import jax
+
+    path = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(DEFAULT_COMPILE_CACHE))
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # untrained-fallback trunks per family (scheduling behaviour only): lets the
@@ -68,6 +94,38 @@ def load_reasoner(ckpt_dir: Optional[str], arch: str = "dense"):
     return model, params, None
 
 
+def load_registry_model(cfg, seed: int):
+    """(model, params, prm_head) for a registry config at its published
+    widths: bf16 weights and an fp32 PRM head, both drawn from ``seed``.
+    Reads no checkpoint."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..core.prm import init_prm_head
+    from ..models import Model
+
+    model = Model(cfg, dtype=jnp.bfloat16)
+    k_lm, k_prm = jax.random.split(jax.random.PRNGKey(seed))
+    params = jax.jit(model.init_params)(k_lm)
+    return model, params, init_prm_head(k_prm, cfg.d_model)
+
+
+def make_engine(model, params, prm_head, *, max_slots: int, seed: int,
+                temperature: float, mixed_step_kernel: str = "fused",
+                step_token_budget: int = 0, prefix_cache: bool = False):
+    """The serving engine ``serve`` drives, for the given weights."""
+    from ..data import tokenizer as tk
+    from ..serving import Engine, EngineConfig, SamplingParams
+
+    return Engine(model, params, EngineConfig(
+        page_size=16, num_pages=NUM_PAGES, max_slots=max_slots,
+        max_pages_per_branch=32, eos_id=tk.EOS,
+        sampling=SamplingParams(temperature=temperature, top_p=0.95),
+        seed=seed, mixed_step_kernel=mixed_step_kernel,
+        step_token_budget=step_token_budget, prefix_cache=prefix_cache),
+        prm_params=prm_head)
+
+
 def serve(policy: str, n: int, num_requests: int, rate_gap: int,
           ckpt: Optional[str], prm_kind: str, window: int, max_tokens: int,
           max_slots: int, seed: int, temperature: float,
@@ -75,24 +133,29 @@ def serve(policy: str, n: int, num_requests: int, rate_gap: int,
           step_token_budget: int = 0, prefix_cache: bool = False,
           admission_policy: str = "fifo",
           deadline: Optional[int] = None,
-          fault_plan: Optional[str] = None) -> dict:
+          fault_plan: Optional[str] = None,
+          model_cfg=None) -> dict:
+    """Serve ``num_requests`` synthetic problems and return the metrics.
+
+    ``model_cfg`` (a ``ModelConfig``) serves that config through
+    ``load_registry_model`` in place of the checkpoint or fallback trunk
+    that ``ckpt``/``arch`` select."""
     import numpy as np
 
     from ..core import OraclePRM, RewardHeadPRM, Scheduler, SchedulerConfig
     from ..core.scheduler import percentile_latency
     from ..data import tasks
-    from ..data import tokenizer as tk
-    from ..serving import (Engine, EngineConfig, FaultInjector, FaultPlan,
-                           SamplingParams)
+    from ..serving import FaultInjector, FaultPlan
 
-    model, params, prm_head = load_reasoner(ckpt, arch)
-    engine = Engine(model, params, EngineConfig(
-        page_size=16, num_pages=4096, max_slots=max_slots,
-        max_pages_per_branch=32, eos_id=tk.EOS,
-        sampling=SamplingParams(temperature=temperature, top_p=0.95),
-        seed=seed, mixed_step_kernel=mixed_step_kernel,
-        step_token_budget=step_token_budget, prefix_cache=prefix_cache),
-        prm_params=prm_head)
+    if model_cfg is not None:
+        model, params, prm_head = load_registry_model(model_cfg, seed)
+    else:
+        model, params, prm_head = load_reasoner(ckpt, arch)
+    engine = make_engine(model, params, prm_head, max_slots=max_slots,
+                         seed=seed, temperature=temperature,
+                         mixed_step_kernel=mixed_step_kernel,
+                         step_token_budget=step_token_budget,
+                         prefix_cache=prefix_cache)
     if prm_kind == "head" and prm_head is not None:
         prm = RewardHeadPRM(engine)
     else:
@@ -123,13 +186,14 @@ def serve(policy: str, n: int, num_requests: int, rate_gap: int,
         if tasks.is_correct(prob, r["answer"]))
     acc = correct / max(num_requests, 1)
     out = {
-        "policy": policy, "n": n, "accuracy": acc,
+        "model": model.cfg.name, "policy": policy, "n": n, "accuracy": acc,
         "p50": percentile_latency(metrics, 50),
         "p90": percentile_latency(metrics, 90),
         "p97": percentile_latency(metrics, 97),
         "p99": percentile_latency(metrics, 99),
         "queue_p50": percentile_latency(metrics, 50, "queue"),
         "decode_steps": metrics["decode_steps"],
+        "generated_tokens": engine.generated_tokens,
         "clock": metrics["clock"],
         "ttfb50": percentile_latency(metrics, 50, "ttfb"),
         # O(buckets x lane-configs) for every family (masked-dt chunk lane
@@ -161,6 +225,8 @@ def serve(policy: str, n: int, num_requests: int, rate_gap: int,
 
 
 def main():
+    from ..configs import REGISTRY, get_config
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--policy", default="sart",
                     choices=["vanilla", "sc", "sart", "sart_noprune",
@@ -170,6 +236,10 @@ def main():
     ap.add_argument("--rate-gap", type=int, default=8,
                     help="decode steps between arrivals")
     ap.add_argument("--ckpt", default="checkpoints/reasoner")
+    ap.add_argument("--model", default=None, choices=sorted(REGISTRY),
+                    help="serve this registry config at its published "
+                         "widths (bf16, seeded weights and PRM head) "
+                         "instead of --ckpt / --arch")
     ap.add_argument("--arch", default="dense",
                     choices=sorted(_FALLBACK_FAMILIES),
                     help="untrained-fallback trunk family (ssm/hybrid "
@@ -210,12 +280,14 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.9)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    setup_compile_cache()
     out = serve(args.policy, args.n, args.requests, args.rate_gap,
                 args.ckpt, args.prm, args.window, args.max_tokens,
                 args.slots, args.seed, args.temperature, args.arch,
                 args.mixed_step_kernel, args.step_token_budget,
                 args.prefix_cache, args.admission_policy, args.deadline,
-                args.fault_plan)
+                args.fault_plan,
+                get_config(args.model) if args.model else None)
     print(json.dumps(out, indent=2))
 
 

@@ -360,6 +360,7 @@ class Engine:
                                  static_argnames=("lane_buckets",))
         self._prefill_cache: Dict[int, callable] = {}
         self.decode_steps_executed = 0
+        self.generated_tokens = 0         # tokens sampled by decode steps
         self.prefill_chunk_steps = 0
 
         # chunked prefill: every family rides the bucketed path. Attention
@@ -1332,6 +1333,7 @@ class Engine:
                         self.state["conv"][:, h.slot:h.slot + 1],
                         self.state["ssd"][:, h.slot:h.slot + 1])
                 self._insert_generated(h)
+        self.generated_tokens += len(out)
         return out
 
     # --------------------------------------------------------------- scoring

@@ -795,10 +795,15 @@ def test_generated_prefix_resample_admits_warm():
         eng.decode_step()
     b2, lg2, _ = eng.finish_prefill(st)
 
-    # bit-exactness: a cold engine prefilling the same resample prompt
+    # a cold engine prefilling the same resample prompt. The warm span's
+    # K/V was written by single-token decode steps and the cold one by
+    # chunked prefill: different f32 matmul shapes round differently
+    # (last-ulp K/V differences, up to ~1e-6 in logits), so the logits
+    # agree to f32 rounding rather than bit for bit
     _, _, cold = _engine(cfg, temperature=0.0, prefix_cache=False)
     bc, lgc, _ = cold.prefill(resample)
-    np.testing.assert_array_equal(np.asarray(lg2), np.asarray(lgc))
+    np.testing.assert_allclose(np.asarray(lg2), np.asarray(lgc),
+                               rtol=1e-5, atol=1e-5)
     k2, v2 = _gather_prefix(eng, b2, len(resample))
     kc, vc = _gather_prefix(cold, bc, len(resample))
     np.testing.assert_allclose(k2, kc, rtol=1e-4, atol=1e-5)

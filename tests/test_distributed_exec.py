@@ -25,7 +25,7 @@ from repro.configs import smoke
 from repro.distributed.logical import activation_rules, standard_rules
 from repro.distributed.sharding import param_pspecs, sanitize_pspecs, \
     shardings
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 from repro.models import Model, cross_entropy_loss
 
 arch = sys_arch = %(arch)r
@@ -43,8 +43,7 @@ ref_logits, _ = model.forward(params, tokens=None if cfg.multimodal
                               else toks, embeds=embeds)
 
 # --- sharded execution on a (2 data x 4 model) mesh
-# (make_mesh_compat: jax 0.4.x has no AxisType/axis_types kwarg)
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = make_auto_mesh((2, 4), ("data", "model"))
 pspecs = sanitize_pspecs(param_pspecs(params), params, mesh)
 sharded_params = jax.device_put(params, shardings(mesh, pspecs))
 rules = standard_rules(("data",))

@@ -12,10 +12,7 @@ from typing import Iterator, List, Optional, Tuple
 import jax
 import numpy as np
 
-try:  # jax internal, stable across the 0.4.x line this repo pins
-    from jax._src import source_info_util as _siu
-except Exception:  # pragma: no cover - defensive: attribution degrades
-    _siu = None
+from jax._src import source_info_util as _siu
 
 
 def iter_eqns(jaxpr) -> Iterator[object]:
@@ -38,12 +35,7 @@ def source_symbol(eqn) -> str:
     (paths shortened to be src-relative), or ``<jax>:fn`` when every
     frame is library code. Line-number-free on purpose: the string is a
     baseline key and must survive unrelated edits."""
-    frames = []
-    if _siu is not None:
-        try:
-            frames = list(_siu.user_frames(eqn.source_info))
-        except Exception:
-            frames = []
+    frames = list(_siu.user_frames(eqn.source_info.traceback))
     for fr in frames:
         file_name = fr.file_name or ""
         if "/repro/" in file_name or file_name.startswith("repro/"):
