@@ -50,7 +50,8 @@ def reward_logit(params: dict, hidden) -> jax.Array:
 @jax.jit
 def reward_from_hidden(params: dict, hidden) -> jax.Array:
     """hidden [..., D] -> rewards [...] in (0, 1)."""
-    return jax.nn.sigmoid(reward_logit(params, hidden))
+    with jax.named_scope("prm_head"):
+        return jax.nn.sigmoid(reward_logit(params, hidden))
 
 
 def prm_head_loss(params: dict, hidden, labels) -> jax.Array:

@@ -51,6 +51,7 @@ import numpy as np
 
 from ..kv import OutOfPagesError
 from ..serving.engine import BranchHandle, Engine
+from ..tracing import span
 from .ensemble import best_of_n, majority_vote
 from .policies import make_policy, select_next
 from .pruning import PruningConfig, RequestMeta, TwoPhasePruner
@@ -275,52 +276,60 @@ class Scheduler:
     # --------------------------------------------------------- batch filling
     def _fill_batch(self):
         """Algorithm 1 lines 3-11: branches first, then prefill requests.
-        With ``preempt``, suspended branches resume with top priority."""
-        while self.engine.free_slots:
-            if self.suspended:
-                h = self.suspended[0]
-                if h.done or not self.engine.resume_branch(h):
+        With ``preempt``, suspended branches resume with top priority.
+        Traced as ``sched.fill`` with the requests admitted and branches
+        spawned."""
+        with span("sched.fill") as fill:
+            admitted = spawned = 0
+            while self.engine.free_slots:
+                if self.suspended:
+                    h = self.suspended[0]
+                    if h.done or not self.engine.resume_branch(h):
+                        self.suspended.popleft()
+                        continue
                     self.suspended.popleft()
-                    continue
-                self.suspended.popleft()
-            elif self.branch_queue:
-                req = self.branch_queue[0]
-                if req.done or req.pending <= 0:
-                    self.branch_queue.popleft()
-                    continue
-                self._spawn_one(req)
-                if req.pending <= 0:
-                    self.branch_queue.popleft()
-            else:
-                # keep as many prefills in flight as the engine can pack
-                # into one mixed step (admission_capacity = max chunk
-                # lanes; 1 without a token budget) — admitting beyond that
-                # would reserve prompts' pages long before any chunk runs,
-                # starving live decode branches into eviction
-                if not self._admit_one():
-                    break
-        # admission consumes no slot (chunks ride the decode step), so a
-        # saturated batch doesn't block it — keep the lanes fed
-        if not self.engine.free_slots:
-            while self._admit_one():
-                pass
-        if self.cfg.preempt and not self.engine.free_slots:
-            self._maybe_preempt()
+                elif self.branch_queue:
+                    req = self.branch_queue[0]
+                    if req.done or req.pending <= 0:
+                        self.branch_queue.popleft()
+                        continue
+                    spawned += self._spawn_one(req)
+                    if req.pending <= 0:
+                        self.branch_queue.popleft()
+                else:
+                    # keep as many prefills in flight as the engine can
+                    # pack into one mixed step (admission_capacity = max
+                    # chunk lanes; 1 without a token budget) — admitting
+                    # beyond that would reserve prompts' pages long before
+                    # any chunk runs, starving live decode branches into
+                    # eviction
+                    if not self._admit_one():
+                        break
+                    admitted += 1
+            # admission consumes no slot (chunks ride the decode step), so
+            # a saturated batch doesn't block it — keep the lanes fed
+            if not self.engine.free_slots:
+                while self._admit_one():
+                    admitted += 1
+            if self.cfg.preempt and not self.engine.free_slots:
+                spawned += self._maybe_preempt()
+            fill.set_metadata(admitted=admitted, spawned=spawned)
 
-    def _maybe_preempt(self):
+    def _maybe_preempt(self) -> bool:
         """Make progress for waiting work when every slot is taken.
 
         Admission consumes no slot (prefill chunks ride the decode step)
         and is handled by ``_fill_batch`` even when the batch is full, so
         only a waiting *branch* spawn justifies suspending the weakest
-        running branch — the victim resumes as soon as a slot frees."""
+        running branch — the victim resumes as soon as a slot frees.
+        Returns whether a branch was spawned in its place."""
         if not self.branch_queue:
-            return
+            return False
         victims = [h for h in self.engine.slots
                    if h is not None
                    and len(self.requests[h.request_id].live) > 1]
         if not victims:
-            return
+            return False
         # never-scored candidates default last_reward=0.0 and would tie
         # below every scored branch — score them first so a strong branch
         # that simply hasn't hit a scoring window isn't the victim
@@ -333,8 +342,7 @@ class Scheduler:
         self.engine.suspend_branch(victim)
         self.suspended.append(victim)
         req = self.branch_queue[0]
-        if not req.done and req.pending > 0:
-            self._spawn_one(req)
+        return not req.done and req.pending > 0 and self._spawn_one(req)
 
     def _admit_one(self) -> bool:
         """Admit one arrived request if the engine's chunk lanes have room
@@ -393,14 +401,16 @@ class Scheduler:
         already past the cached tokens and fewer chunks to drain. Only
         engines explicitly configured with ``chunked_prefill=False``
         return an already-done state and keep the seed's one-tick
-        synchronous accounting."""
-        req.prefill_state = self.engine.begin_prefill(req.prompt)
-        if req.prefill_state.done:
-            req.first_service = self.clock    # seed-exact sync accounting
-            self.clock += 1               # legacy synchronous prefill tick
-            self._harvest_prefill(req)
-        else:
-            self.prefilling.append(req)
+        synchronous accounting. Traced as ``sched.admit``."""
+        with span("sched.admit", request_id=req.request_id,
+                  prompt_tokens=len(req.prompt)):
+            req.prefill_state = self.engine.begin_prefill(req.prompt)
+            if req.prefill_state.done:
+                req.first_service = self.clock  # seed-exact sync accounting
+                self.clock += 1           # legacy synchronous prefill tick
+                self._harvest_prefill(req)
+            else:
+                self.prefilling.append(req)
 
     def _harvest_prefill(self, req: Request):
         """Prefill finished: collect its outputs, queue N branch spawns.
@@ -452,16 +462,19 @@ class Scheduler:
     def _rebase_initial_width(self) -> int:
         return max(self.cfg.n // 2, 1)
 
-    def _spawn_one(self, req: Request):
+    def _spawn_one(self, req: Request) -> bool:
+        """Spawn one of ``req``'s pending branches; False when the engine
+        had no slot or pages for it."""
         h = self.engine.spawn_branch(
             req.request_id, req.prefix_blocks, req.last_logits,
             req.ssm_state, len(req.prompt), prompt_tokens=req.prompt)
         if h is None:
-            return
+            return False
         if req.first_branch < 0:
             req.first_branch = self.clock   # time-to-first-branch anchor
         req.live[h.branch_id] = h
         req.pending -= 1
+        return True
 
     # -------------------------------------------------------------- decoding
     def _decode_window(self):
@@ -489,13 +502,16 @@ class Scheduler:
             # a faulty-but-alive engine can report slow steps (deadline
             # pressure): charge the extra ticks the step actually cost
             self.clock += 1 + getattr(self.engine, "last_step_penalty", 0)
-            if self._poll_prefills():
-                # seed parity: branches spawned the moment prefill finished;
-                # refill mid-window instead of waiting out the window
-                self._fill_batch()
-            self._check_completions()
-            self.timeline.record(self.clock, self.engine.num_active,
-                                 self.engine.live_tokens())
+            with span("sched.step_tail") as tail:
+                if self._poll_prefills():
+                    # seed parity: branches spawned the moment prefill
+                    # finished; refill mid-window instead of waiting out
+                    # the window
+                    self._fill_batch()
+                completed = self._check_completions()
+                self.timeline.record(self.clock, self.engine.num_active,
+                                     self.engine.live_tokens())
+                tail.set_metadata(completed=completed)
 
     def _evict_longest(self) -> bool:
         """Memory pressure: force-complete live branches, longest first,
@@ -575,7 +591,10 @@ class Scheduler:
         if restart is not None:
             restart()
 
-    def _check_completions(self):
+    def _check_completions(self) -> int:
+        """Complete every branch that sampled EOS or reached the token
+        cap; returns how many did."""
+        completed = 0
         for h in list(self.engine.slots):
             if h is None or h.done:
                 continue  # freed earlier this pass (sibling's early stop)
@@ -585,6 +604,8 @@ class Scheduler:
             if eos or full:
                 self._complete_branch(req, h, truncated=full and not eos)
                 self._maybe_finalize(req)
+                completed += 1
+        return completed
 
     def _complete_branch(self, req: Request, h: BranchHandle,
                          truncated: bool = False):
@@ -600,27 +621,36 @@ class Scheduler:
 
     # ----------------------------------------------------------- bookkeeping
     def _window_bookkeeping(self):
-        """Pruning / early-stop checks at window granularity (lines 23-41)."""
-        for req in list(self.requests.values()):
-            if req.done or req.meta is None:
-                continue
-            if self.cfg.policy == "rebase":
-                self._rebase_step(req)
-            elif req.live and self.pruner.cfg.enabled:
-                # suspended branches (slot == -1) hold no engine row; they
-                # are scored/pruned once resumed
-                handles = [h for h in req.live.values() if h.slot >= 0]
-                if not handles:
+        """Pruning / early-stop checks at window granularity (lines 23-41).
+        Traced as ``sched.prune`` with the reward-head calls made and the
+        branches pruned."""
+        with span("sched.prune") as prune:
+            scored = pruned = 0
+            for req in list(self.requests.values()):
+                if req.done or req.meta is None:
                     continue
-                rewards = self.prm.score(req, handles)
-                by_id = {h.branch_id: r for h, r in zip(handles, rewards)}
-                for h, r in zip(handles, rewards):
-                    h.last_reward = r
-                    h.scored = True
-                for bid in self.pruner.select_prunes(req.meta, by_id):
-                    h = req.live.pop(bid)
-                    self.engine.free_branch(h)
-            self._maybe_finalize(req)
+                if self.cfg.policy == "rebase":
+                    scored += bool(req.live)
+                    pruned += self._rebase_step(req)
+                elif req.live and self.pruner.cfg.enabled:
+                    # suspended branches (slot == -1) hold no engine row;
+                    # they are scored/pruned once resumed
+                    handles = [h for h in req.live.values() if h.slot >= 0]
+                    if not handles:
+                        continue
+                    rewards = self.prm.score(req, handles)
+                    scored += 1
+                    by_id = {h.branch_id: r
+                             for h, r in zip(handles, rewards)}
+                    for h, r in zip(handles, rewards):
+                        h.last_reward = r
+                        h.scored = True
+                    for bid in self.pruner.select_prunes(req.meta, by_id):
+                        h = req.live.pop(bid)
+                        self.engine.free_branch(h)
+                        pruned += 1
+                self._maybe_finalize(req)
+            prune.set_metadata(scored=scored, pruned=pruned)
 
     def _maybe_finalize(self, req: Request):
         if req.done or req.meta is None:
@@ -630,33 +660,40 @@ class Scheduler:
             self._finalize(req)
 
     def _finalize(self, req: Request):
-        """Early stop: terminate remaining branches, pick the final answer."""
-        for h in list(req.live.values()):
-            self.engine.free_branch(h)
-        req.live.clear()
-        req.pending = 0
-        if req.prefix_blocks is not None:
-            self.engine.release_prefix(req.prefix_blocks)
-            req.prefix_blocks = None
-        if self.cfg.policy == "sc":
-            req.final_answer = majority_vote(req.completed, self.answer_fn)
-        else:
-            req.final_answer = best_of_n(req.completed, self.answer_fn)
-        req.finish = self.clock
-        if req.had_fault:
-            self.fault_counters["recovered"] += 1
+        """Early stop: terminate remaining branches, pick the final answer.
+        Traced as ``sched.finalize``."""
+        with span("sched.finalize", request_id=req.request_id,
+                  completed=len(req.completed),
+                  pruned=req.meta.num_pruned):
+            for h in list(req.live.values()):
+                self.engine.free_branch(h)
+            req.live.clear()
+            req.pending = 0
+            if req.prefix_blocks is not None:
+                self.engine.release_prefix(req.prefix_blocks)
+                req.prefix_blocks = None
+            if self.cfg.policy == "sc":
+                req.final_answer = majority_vote(req.completed,
+                                                 self.answer_fn)
+            else:
+                req.final_answer = best_of_n(req.completed, self.answer_fn)
+            req.finish = self.clock
+            if req.had_fault:
+                self.fault_counters["recovered"] += 1
 
     # ---------------------------------------------------------------- rebase
-    def _rebase_step(self, req: Request):
-        """Reward-guided tree search: cull weak leaves, fork strong ones."""
+    def _rebase_step(self, req: Request) -> int:
+        """Reward-guided tree search: cull weak leaves, fork strong ones.
+        Returns the leaves culled."""
         if not req.live:
-            return
+            return 0
         handles = list(req.live.values())
         rewards = np.asarray(self.prm.score(req, handles))
         for h, r in zip(handles, rewards):
             h.last_reward = float(r)
             h.scored = True
         # cull leaves far below the best (soft budget reallocation)
+        culled = 0
         if len(handles) > 1:
             weights = np.exp((rewards - rewards.max()) / self.cfg.rebase_temp)
             weights /= weights.sum()
@@ -666,6 +703,7 @@ class Scheduler:
                     req.meta.num_pruned += 1
                     del req.live[h.branch_id]
                     self.engine.free_branch(h)
+                    culled += 1
         # expand best leaves while under budget and slots are free
         total = (len(req.live) + req.meta.num_completed
                  + req.pending)
@@ -678,6 +716,7 @@ class Scheduler:
                 break
             req.live[child.branch_id] = child
             total += 1
+        return culled
 
     # ----------------------------------------------------- checkpoint/restore
     def snapshot(self) -> Dict:

@@ -55,7 +55,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +74,7 @@ from ..models.mamba2 import (init_mamba2_state, mamba2_decode,
                              mamba2_forward)
 from ..models.model import Model
 from ..models.moe import apply_moe
+from ..tracing import span
 from .sampling import SamplingParams, sample
 
 
@@ -290,6 +291,28 @@ class BranchHandle:
     ssm_snaps: Optional[dict] = None  # {token boundary: (conv, ssd)}
 
 
+class StepRows(NamedTuple):
+    """Host inputs of one call of the step program, as the host side
+    leaves them before the upload: per row its token, position, block
+    table and attended length (the ``max_slots`` decode rows first, then
+    each chunk lane padded to its bucket), the decode slots' validity
+    mask, each lane's valid chunk length and running SSM state (empty
+    for attention-only configs), the lanes' buckets, and ``useful``: the
+    active decode rows plus the valid chunk rows."""
+    tokens: np.ndarray
+    positions: np.ndarray
+    block_tables: np.ndarray
+    lengths: np.ndarray
+    slot_valid: np.ndarray
+    chunk_lens: np.ndarray
+    chunk_state: dict
+    lane_buckets: tuple
+    useful: int
+
+
+_NO_LANES = np.zeros((0,), np.int32)   # chunk_lens of a step with no lanes
+
+
 @dataclasses.dataclass(frozen=True)
 class StepVariant:
     """One reachable traced shape of ``Engine._step_fn``.
@@ -360,6 +383,11 @@ class Engine:
                                  static_argnames=("lane_buckets",))
         self._prefill_cache: Dict[int, callable] = {}
         self.decode_steps_executed = 0
+        # rows the step program ran (decode slots plus lane buckets) and
+        # rows that carried work (active slots plus valid chunk rows): the
+        # difference is padding
+        self.rows_computed = 0
+        self.rows_useful = 0
         self.generated_tokens = 0         # tokens sampled by decode steps
         self.prefill_chunk_steps = 0
 
@@ -436,7 +464,8 @@ class Engine:
         if not exact:
             st = self._new_chunked_state(prompt)
             while not st.done:
-                self._advance_chunks([st], piggyback=False)
+                self._run_step([st], self._with_lanes(
+                    self._inert_rows(), [st], self._chunk_bucket(st)))
             return st.blocks, st.last_logits, st.ssm_state
         cfg, mc = self.cfg, self.model.cfg
         s = len(prompt)
@@ -669,77 +698,92 @@ class Engine:
         return (tokens, idx.astype(np.int32), block_tables,
                 idx.astype(np.int32), chunk_len)
 
-    def _advance_chunks(self, sts: List[ChunkedPrefillState],
-                        piggyback: bool, bucket: int = 0,
-                        cows: Sequence[tuple] = (),
-                        tree: Optional[dict] = None):
-        """Run one chunk of each state in ``sts`` through the step program
-        as concurrent lanes (``sts`` comes from ``pack_chunk_lanes``; the
-        legacy path passes a single state). With ``piggyback`` the caller
-        (``decode_step``) supplies the live decode rows plus the step's
-        CoW page copies (``cows``, folded into the same dispatch as the
-        chunk K/V writes — see ``_cow_arrays``); standalone draining pads
-        with inert rows (sentinel block tables drop their page writes,
-        and the slot-validity mask freezes the per-slot SSM states) so
-        active branches are never advanced.
+    def _decode_rows(self, active: int) -> StepRows:
+        """The live decode slots as a step's rows, no lanes yet."""
+        return StepRows(self._tokens, self._positions, self._block_tables,
+                        self._lengths, self._active, _NO_LANES, {}, (),
+                        active)
 
-        ssm/hybrid configs thread each lane's running per-layer (conv,
-        ssd) state through the step (``chunk_*`` keys, stacked along a
-        lane axis) and get it back advanced by exactly that lane's chunk
-        length — pad rows are identity transitions under the masked-dt
-        scan. With the prefix cache, each lane snapshots its SSM state at
-        page-aligned chunk boundaries and a finished prompt's full pages
-        are inserted into the radix."""
-        cfg, mc = self.cfg, self.model.cfg
+    def _inert_rows(self) -> StepRows:
+        """Decode rows that do nothing, for draining a prompt's chunks
+        outside a decode step: sentinel block tables drop their page
+        writes, and the slot-validity mask freezes the per-slot SSM
+        states, so active branches are never advanced."""
+        cfg = self.cfg
         B = cfg.max_slots
-        if not bucket:
-            bucket = max(self._chunk_bucket(st) for st in sts)
+        return StepRows(np.zeros((B,), np.int32), np.zeros((B,), np.int32),
+                        np.full((B, cfg.max_pages_per_branch),
+                                cfg.num_pages, np.int32),
+                        np.zeros((B,), np.int32), np.zeros((B,), bool),
+                        _NO_LANES, {}, (), 0)
+
+    def _with_lanes(self, rows: StepRows, sts: List[ChunkedPrefillState],
+                    bucket: int) -> StepRows:
+        """``rows`` plus one chunk of each state in ``sts`` as concurrent
+        lanes, each padded to the step's shared ``bucket`` (``sts`` comes
+        from ``pack_chunk_lanes``; standalone draining passes a single
+        state). ssm/hybrid configs thread each lane's running per-layer
+        (conv, ssd) state through the step (``chunk_*`` keys, stacked
+        along a lane axis)."""
         lanes = [self._chunk_inputs(st, bucket) for st in sts]
         chunk_lens = np.asarray([ln[4] for ln in lanes], np.int32)
-        if piggyback:
-            d_tokens, d_positions = self._tokens, self._positions
-            d_bt, d_lengths = self._block_tables, self._lengths
-            slot_valid = self._active
-        else:
-            d_tokens = np.zeros((B,), np.int32)
-            d_positions = np.zeros((B,), np.int32)
-            d_bt = np.full((B, cfg.max_pages_per_branch), cfg.num_pages,
-                           np.int32)
-            d_lengths = np.zeros((B,), np.int32)
-            slot_valid = np.zeros((B,), bool)
         chunk_state = {}
-        if mc.uses_ssm:
+        if self.model.cfg.uses_ssm:
             chunk_state = {
                 "conv": jnp.concatenate([st.ssm_state[0] for st in sts], 1),
                 "ssd": jnp.concatenate([st.ssm_state[1] for st in sts], 1)}
-        lane_buckets = (bucket,) * len(sts)
-        self._buckets_used.add((bucket, len(sts)))
-        cow_src, cow_dst = self._cow_arrays(cows)
+        return StepRows(
+            np.concatenate([rows.tokens] + [ln[0] for ln in lanes]),
+            np.concatenate([rows.positions] + [ln[1] for ln in lanes]),
+            np.concatenate([rows.block_tables] + [ln[2] for ln in lanes]),
+            np.concatenate([rows.lengths] + [ln[3] for ln in lanes]),
+            rows.slot_valid, chunk_lens, chunk_state, (bucket,) * len(sts),
+            rows.useful + int(chunk_lens.sum()))
+
+    def _run_step(self, sts: List[ChunkedPrefillState],
+                        rows: StepRows, cow: Optional[tuple] = None,
+                        tree: Optional[dict] = None):
+        """Upload ``rows`` and run the step program once over them, then
+        advance each state in ``sts`` (the lanes ``rows`` carries, in
+        order) by its chunk. ``cow`` is the step's CoW page pairs from
+        ``_cow_arrays`` (folded into the same dispatch as the chunk K/V
+        writes) and ``tree`` the decode rows' dedup map; both default to
+        their sentinels, for steps whose decode rows are inert.
+
+        Each lane gets its running SSM state back advanced by exactly its
+        chunk length (pad rows are identity transitions under the
+        masked-dt scan). With the prefix cache, each lane snapshots its
+        SSM state at page-aligned chunk boundaries and a finished prompt's
+        full pages are inserted into the radix."""
+        cfg, mc = self.cfg, self.model.cfg
+        B = cfg.max_slots
+        cow_src, cow_dst = cow if cow is not None else self._cow_arrays(())
         if tree is None:
             tree = self._tree_map()     # sentinel: decode rows are inert
         next_tokens, hidden, logits, new_state = self._step_jit(
-            self.params, self.state,
-            jnp.asarray(np.concatenate([d_tokens] + [ln[0] for ln in lanes])),
-            jnp.asarray(np.concatenate([d_positions]
-                                       + [ln[1] for ln in lanes])),
-            jnp.asarray(np.concatenate([d_bt] + [ln[2] for ln in lanes])),
-            jnp.asarray(np.concatenate([d_lengths]
-                                       + [ln[3] for ln in lanes])),
-            self._next_rng(), chunk_state, jnp.asarray(chunk_lens),
-            jnp.asarray(slot_valid), cow_src, cow_dst, tree,
-            lane_buckets=lane_buckets)
+            self.params, self.state, jnp.asarray(rows.tokens),
+            jnp.asarray(rows.positions), jnp.asarray(rows.block_tables),
+            jnp.asarray(rows.lengths), self._next_rng(), rows.chunk_state,
+            jnp.asarray(rows.chunk_lens), jnp.asarray(rows.slot_valid),
+            cow_src, cow_dst, tree, lane_buckets=rows.lane_buckets)
         new_state = dict(new_state)
-        if mc.uses_ssm:
+        if sts and mc.uses_ssm:
             c_conv = new_state.pop("chunk_conv")      # [L, n_lanes, ...]
             c_ssd = new_state.pop("chunk_ssd")
             for i, st in enumerate(sts):
                 st.ssm_state = (c_conv[:, i:i + 1], c_ssd[:, i:i + 1])
         self.state.update(new_state)
+        self.rows_computed += len(rows.tokens)
+        self.rows_useful += rows.useful
+        if not sts:
+            return next_tokens, hidden
+        bucket = rows.lane_buckets[0]
+        self._buckets_used.add((bucket, len(sts)))
         self.prefill_chunk_steps += len(sts)
         self.mixed_steps_executed += 1
         ps = cfg.page_size
         for i, st in enumerate(sts):
-            cl = int(chunk_lens[i])
+            cl = int(rows.chunk_lens[i])
             st.next_pos += cl
             if (mc.uses_ssm and st.ssm_snaps is not None
                     and st.next_pos % ps == 0):
@@ -1069,10 +1113,11 @@ class Engine:
             # gather is backend-defined) and the garbage is discarded
             src = jnp.minimum(cow_src, cfg.num_pages - 1)
             state = dict(state)
-            state["k_pages"] = state["k_pages"].at[:, :, cow_dst].set(
-                state["k_pages"][:, :, src], mode="drop")
-            state["v_pages"] = state["v_pages"].at[:, :, cow_dst].set(
-                state["v_pages"][:, :, src], mode="drop")
+            with jax.named_scope("kv_write"):
+                state["k_pages"] = state["k_pages"].at[:, :, cow_dst].set(
+                    state["k_pages"][:, :, src], mode="drop")
+                state["v_pages"] = state["v_pages"].at[:, :, cow_dst].set(
+                    state["v_pages"][:, :, src], mode="drop")
         # static: lane row offsets into the step's row axis
         lane_off = []
         off = nS
@@ -1124,10 +1169,13 @@ class Engine:
                     pos_in = jnp.broadcast_to(pos_in[..., None], (B, 1, 3))
                 q, k = _rotate(mc, q, k, pos_in)
                 # write new token's k/v into pages ([kv, page, slot, hd])
-                kp = kp.at[:, page_of, slot_in_page].set(
-                    jnp.moveaxis(k[:, 0], 1, 0), mode="drop")
-                vp = vp.at[:, page_of, slot_in_page].set(
-                    jnp.moveaxis(v[:, 0], 1, 0), mode="drop")
+                with jax.named_scope("kv_write"):
+                    kp = kp.at[:, page_of, slot_in_page].set(
+                        jnp.moveaxis(k[:, 0], 1, 0), mode="drop")
+                    vp = vp.at[:, page_of, slot_in_page].set(
+                        jnp.moveaxis(v[:, 0], 1, 0), mode="drop")
+
+                @jax.named_scope("attn_decode")
                 def slot_attention():
                     """Decode-slot attention; with the tree map, shared
                     ancestor pages stream once per fork group and
@@ -1155,18 +1203,20 @@ class Engine:
                     att_parts = [slot_attention()]
                     for i, bk in enumerate(lane_buckets):
                         o = lane_off[i]
-                        att_parts.append(paged_flash_prefill(
-                            q[o:o + bk, 0], kp, vp, block_tables[o],
-                            positions[o], chunk_lens[i],
-                            use_kernel=on_tpu))
+                        with jax.named_scope("attn_prefill"):
+                            att_parts.append(paged_flash_prefill(
+                                q[o:o + bk, 0], kp, vp, block_tables[o],
+                                positions[o], chunk_lens[i],
+                                use_kernel=on_tpu))
                     att = jnp.concatenate(att_parts, 0)
                 elif B > nS:
                     # mixed_step_kernel="decode" fallback: decode slots
                     # and chunk rows ride one per-branch call (the tree
                     # map is rejected for this combination in __init__)
-                    att = paged_attention(
-                        q[:, 0], kp, vp, block_tables, lengths + 1,
-                        use_kernel=on_tpu)
+                    with jax.named_scope("attn_decode"):
+                        att = paged_attention(
+                            q[:, 0], kp, vp, block_tables, lengths + 1,
+                            use_kernel=on_tpu)
                 else:
                     att = slot_attention()
                 y = att.reshape(B, 1, -1) @ layer_p["attn"]["wo"]
@@ -1203,11 +1253,12 @@ class Engine:
                 mix = mix * 0.5
             x = x + mix
             if mc.d_ff:
-                h2 = apply_norm(mc, layer_p["norm2"], x)
-                if mc.uses_moe:
-                    y, _ = apply_moe(mc, layer_p["moe"], h2)
-                else:
-                    y = apply_mlp(mc, layer_p["mlp"], h2)
+                with jax.named_scope("mlp"):
+                    h2 = apply_norm(mc, layer_p["norm2"], x)
+                    if mc.uses_moe:
+                        y, _ = apply_moe(mc, layer_p["moe"], h2)
+                    else:
+                        y = apply_mlp(mc, layer_p["mlp"], h2)
                 x = x + y
             return x, outs
 
@@ -1219,12 +1270,14 @@ class Engine:
             scanned_in["chunk_conv"] = chunk_state["conv"]
             scanned_in["chunk_ssd"] = chunk_state["ssd"]
         x, new_state = jax.lax.scan(layer, x, scanned_in)
-        x = apply_norm(mc, params["final_norm"], x)
-        hidden = x[:, 0]
-        logits = unembed(mc, params["embed"], hidden)
-        keys = jax.random.split(rng, B)
-        next_tokens = jax.vmap(lambda r, l: sample(r, l, cfg.sampling))(
-            keys, logits)
+        with jax.named_scope("unembed"):
+            x = apply_norm(mc, params["final_norm"], x)
+            hidden = x[:, 0]
+            logits = unembed(mc, params["embed"], hidden)
+        with jax.named_scope("sample"):
+            keys = jax.random.split(rng, B)
+            next_tokens = jax.vmap(lambda r, l: sample(r, l, cfg.sampling))(
+                keys, logits)
         # hidden stays in the model compute dtype: its only consumer is
         # the PRM head, whose fp32 weights promote the matmul operand at
         # the use site — an eager upcast here would ship d_model fp32
@@ -1241,76 +1294,95 @@ class Engine:
         Handles host-side page accounting (boundary alloc + CoW) *before* the
         jit'd step, then appends the sampled token to each active branch.
         Returns {slot: new_token}.
+
+        Traced as ``engine.step`` with four children in order (see
+        ``repro.tracing``): ``engine.prepare`` (page accounting, lane
+        packing, the step's host arrays), ``engine.dispatch`` (uploads and
+        the step program's enqueue), ``engine.sync`` (the wait for the
+        sampled tokens) and ``engine.harvest`` (the per-slot loop).
         """
-        cfg, mc = self.cfg, self.model.cfg
+        cfg = self.cfg
         if not self._active.any() and not self._pending_prefills:
             return {}
-        # page accounting for the token about to be written
-        if mc.uses_attention:
-            cap = cfg.max_pages_per_branch * cfg.page_size
-            for h in self.slots:
-                if h is not None and h.blocks.length + 1 > cap:
-                    # static block table full: surface as memory pressure so
-                    # the scheduler's evict-longest path force-completes the
-                    # branch instead of the table-refresh assert tripping
-                    raise OutOfPagesError(
-                        "branch at block-table capacity "
-                        f"({cap} tokens)")
-            if self.pages_needed_for_step() > self.allocator.free_pages:
-                raise OutOfPagesError(
-                    "decode step needs more pages than are free")
-            cows = []
-            # reprolint REP002 baselined: the pages_needed_for_step
-            # pre-check above reserves this loop's worst case, so
-            # append_token cannot raise mid-way
-            for h in self.slots:
-                if h is None:
-                    continue
-                cow = self.allocator.append_token(h.blocks)
-                if cow is not None:
-                    cows.append(cow)
-                self._refresh_block_table(h)
-        else:
-            cows = []
+        with span("engine.step"):
+            with span("engine.prepare") as prepare:
+                cows = self._account_pages()
+                # pack only after the page accounting above: an
+                # OutOfPagesError abort must not charge skipped prefills'
+                # starvation counters for a step that never ran. The
+                # step's CoW copies ride the step program itself (one
+                # fused gather/scatter batched with the chunk K/V writes
+                # — no separate host dispatch, whatever the lane count)
+                lanes, bucket = self._pack_lanes()
+                # the tree dedup map reflects POST-accounting topology: CoW
+                # and boundary allocation above already diverged any page
+                # this step writes, so no fork group's shared span covers
+                # a written page
+                tree = (self._tree_map([h.blocks if h is not None else None
+                                        for h in self.slots])
+                        if cfg.decode_kernel == "tree" else None)
+                cow = self._cow_arrays(cows)
+                active = self.num_active
+                rows = self._decode_rows(active)
+                if lanes:
+                    rows = self._with_lanes(rows, lanes, bucket)
+                prepare.set_metadata(rows=active, lanes=len(lanes),
+                                     bucket=bucket, cows=len(cows))
+            with span("engine.dispatch"):
+                next_tokens, hidden = self._run_step(lanes, rows, cow, tree)
+                self._last_hidden = hidden[:cfg.max_slots]
+                self.decode_steps_executed += 1
+            # the one mandated sync per step: sampled tokens drive
+            # host-side branch bookkeeping (EOS detection, page
+            # accounting) before the next dispatch can be built
+            with span("engine.sync"):
+                toks = np.asarray(next_tokens)  # reprolint: disable=REP005
+            with span("engine.harvest"):
+                out = self._harvest(toks)
+        return out
+
+    def _account_pages(self) -> List[tuple]:
+        """Page accounting for the token each active branch is about to
+        write: boundary allocation and copy-on-write. Returns the step's
+        (old, new) CoW page pairs."""
+        cfg, mc = self.cfg, self.model.cfg
+        cows: List[tuple] = []
+        if not mc.uses_attention:
             for h in self.slots:
                 if h is not None:
                     h.blocks.length += 1
+            return cows
+        cap = cfg.max_pages_per_branch * cfg.page_size
+        for h in self.slots:
+            if h is not None and h.blocks.length + 1 > cap:
+                # static block table full: surface as memory pressure so
+                # the scheduler's evict-longest path force-completes the
+                # branch instead of the table-refresh assert tripping
+                raise OutOfPagesError(
+                    "branch at block-table capacity "
+                    f"({cap} tokens)")
+        if self.pages_needed_for_step() > self.allocator.free_pages:
+            raise OutOfPagesError(
+                "decode step needs more pages than are free")
+        # reprolint REP002 baselined: the pages_needed_for_step
+        # pre-check above reserves this loop's worst case, so
+        # append_token cannot raise mid-way
+        for h in self.slots:
+            if h is None:
+                continue
+            cow = self.allocator.append_token(h.blocks)
+            if cow is not None:
+                cows.append(cow)
+            self._refresh_block_table(h)
+        return cows
 
-        # pack only after the page accounting above: an OutOfPagesError
-        # abort must not charge skipped prefills' starvation counters for
-        # a step that never ran. The step's CoW copies ride the step
-        # program itself (one fused gather/scatter batched with the chunk
-        # K/V writes — no separate host dispatch, whatever the lane count)
-        lanes, bucket = self._pack_lanes()
-        # the tree dedup map reflects POST-accounting topology: CoW and
-        # boundary allocation above already diverged any page this step
-        # writes, so no fork group's shared span covers a written page
-        tree = (self._tree_map([h.blocks if h is not None else None
-                                for h in self.slots])
-                if self.cfg.decode_kernel == "tree" else None)
-        if lanes:
-            next_tokens, hidden = self._advance_chunks(
-                lanes, piggyback=True, bucket=bucket, cows=cows, tree=tree)
-        else:
-            cow_src, cow_dst = self._cow_arrays(cows)
-            if tree is None:
-                tree = self._tree_map()
-            next_tokens, hidden, _, new_state = self._step_jit(
-                self.params, self.state, jnp.asarray(self._tokens),
-                jnp.asarray(self._positions), jnp.asarray(self._block_tables),
-                jnp.asarray(self._lengths), self._next_rng(), {},
-                jnp.zeros((0,), jnp.int32), jnp.asarray(self._active),
-                cow_src, cow_dst, tree, lane_buckets=())
-            self.state.update(new_state)
-        self._last_hidden = hidden[:cfg.max_slots]
-        self.decode_steps_executed += 1
-
+    def _harvest(self, toks: np.ndarray) -> Dict[int, int]:
+        """Append each active branch's sampled token and advance its slot;
+        page-aligned branches publish their generated pages to the prefix
+        cache. Returns {slot: new_token}."""
+        mc = self.model.cfg
+        ps = self.cfg.page_size
         out: Dict[int, int] = {}
-        # the one mandated sync per step: sampled tokens drive host-side
-        # branch bookkeeping (EOS detection, page accounting) before the
-        # next dispatch can be built
-        toks = np.asarray(next_tokens)  # reprolint: disable=REP005
-        ps = cfg.page_size
         for slot, h in enumerate(self.slots):
             if h is None:
                 continue
@@ -1338,12 +1410,14 @@ class Engine:
 
     # --------------------------------------------------------------- scoring
     def score_slots(self) -> np.ndarray:
-        """PRM reward per slot from the cached last hidden state."""
+        """PRM reward per slot from the cached last hidden state (traced
+        as ``prm.head``)."""
         if self.prm_params is None:
             raise RuntimeError("engine has no PRM head")
         from ..core.prm import reward_from_hidden
-        r = reward_from_hidden(self.prm_params, self._last_hidden)
-        return np.asarray(r)
+        with span("prm.head", slots=self.cfg.max_slots):
+            r = reward_from_hidden(self.prm_params, self._last_hidden)
+            return np.asarray(r)
 
 
 # --------------------------------------------------------------------- helpers

@@ -12,7 +12,7 @@ Everything else should stay on device or ride that one sync.
 Detection is a per-function taint walk, scoped to ``serving/``:
 
   * **sources** — names assigned (incl. tuple unpacking) from a call
-    whose callee ends in ``_jit`` or is ``_advance_chunks`` /
+    whose callee ends in ``_jit`` or is ``_run_step`` /
     ``decode_step`` (the step dispatchers);
   * **propagation** — subscripts/slices of tainted names stay device
     values;
@@ -27,7 +27,7 @@ from typing import Iterator, Set
 from ..framework import (FileContext, Finding, ProjectContext, Rule,
                          dotted_name, register)
 
-_STEP_CALLEES = ("_advance_chunks", "decode_step")
+_STEP_CALLEES = ("_run_step", "decode_step")
 _SINK_CALLS = ("np.asarray", "np.array", "numpy.asarray", "numpy.array",
                "float", "int", "bool")
 _SINK_METHODS = ("item", "tolist", "block_until_ready")
