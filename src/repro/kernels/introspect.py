@@ -12,7 +12,10 @@ The index maps stored here are the exact callables handed to Pallas. For a
 kernel using ``PrefetchScalarGridSpec`` they take ``(*grid_indices,
 *scalar_prefetch_refs)``; evaluating them with concrete integers and numpy
 arrays (as stepcheck does) exercises the same arithmetic — including the
-OOB-sentinel clamps — that runs on device.
+OOB-sentinel clamps — that runs on device. An operand the kernel fetches
+itself (it stays in HBM and the kernel's own loop issues async copies)
+declares that loop's extents as ``loop``; its index map takes the loop
+indices after the grid's and is the function the kernel's copies call.
 """
 from __future__ import annotations
 
@@ -31,12 +34,18 @@ class BlockMapping:
     ``index_map`` returns *block* indices: element range covered along
     dim d is ``idx[d] * block_shape[d] : (idx[d] + 1) * block_shape[d]``,
     which the bounds verifier checks against ``array_shape[d]``.
+
+    ``loop`` is empty for a Pallas-pipelined operand. Non-empty, the
+    operand stays in HBM (``memory_space=pl.ANY``) and the kernel copies
+    block ``index_map(*grid_indices, *loop_indices, *scalar_refs)`` for
+    every point of its own loop over ``loop``'s extents.
     """
 
     name: str
     array_shape: Tuple[int, ...]
     block_shape: Tuple[int, ...]
     index_map: IndexMap
+    loop: Tuple[int, ...] = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +76,7 @@ def block_specs(mappings: Tuple[BlockMapping, ...]) -> List[pl.BlockSpec]:
 
     This is the only path from a :class:`KernelGrid` to Pallas — kernels
     must not hand-build specs next to it, or the analyzed geometry and the
-    launched geometry can drift.
+    launched geometry can drift. An operand with a ``loop`` stays in HBM.
     """
-    return [pl.BlockSpec(m.block_shape, m.index_map) for m in mappings]
+    return [pl.BlockSpec(memory_space=pl.ANY) if m.loop
+            else pl.BlockSpec(m.block_shape, m.index_map) for m in mappings]

@@ -64,6 +64,8 @@ import numpy as np
 from ..kernels.flash_prefill.ops import paged_flash_prefill
 from ..kernels.paged_attention.ops import (paged_attention,
                                            paged_tree_attention)
+from ..kernels.paged_attention.paged_attention import (block_walk,
+                                                        blocks_run)
 from ..kv import (BranchBlocks, OutOfPagesError, PageAllocator,
                   PrefixCache, tree_decode_map)
 from ..models.attention import _project_qkv, _rotate
@@ -388,6 +390,15 @@ class Engine:
         # difference is padding
         self.rows_computed = 0
         self.rows_useful = 0
+        # compute blocks the per-branch decode kernel runs over the decode
+        # rows, and what it would run walking every row's whole table:
+        # their ratio is the share of the tables the kernel walks
+        self.attn_blocks_run = 0
+        self.attn_blocks_grid = 0
+        if mc.uses_attention:
+            self._attn_walk = block_walk(
+                cfg.page_size, hd, kv, self.state["k_pages"].dtype.itemsize,
+                cfg.max_pages_per_branch)
         self.generated_tokens = 0         # tokens sampled by decode steps
         self.prefill_chunk_steps = 0
 
@@ -1326,6 +1337,8 @@ class Engine:
                 rows = self._decode_rows(active)
                 if lanes:
                     rows = self._with_lanes(rows, lanes, bucket)
+                if self.model.cfg.uses_attention and tree is None:
+                    self._count_attn_blocks()
                 prepare.set_metadata(rows=active, lanes=len(lanes),
                                      bucket=bucket, cows=len(cows))
             with span("engine.dispatch"):
@@ -1340,6 +1353,14 @@ class Engine:
             with span("engine.harvest"):
                 out = self._harvest(toks)
         return out
+
+    def _count_attn_blocks(self):
+        """Add this step's decode rows to ``attn_blocks_run`` and
+        ``attn_blocks_grid``: each slot attends ``length + 1`` tokens (an
+        empty slot one), counted by the kernel's own block rule."""
+        self.attn_blocks_run += int(
+            blocks_run(self._lengths + 1, *self._attn_walk).sum())
+        self.attn_blocks_grid += len(self._lengths) * self._attn_walk[1]
 
     def _account_pages(self) -> List[tuple]:
         """Page accounting for the token each active branch is about to

@@ -160,3 +160,26 @@ def test_cow_arrays_reuses_sentinel_pair_when_no_cow():
     # and the cached pair was not clobbered by the real-CoW call
     again = eng._cow_arrays([])
     assert again[0] is s1[0] and int(again[0][0]) == eng.cfg.num_pages
+
+
+def test_attention_block_counters():
+    """``attn_blocks_run`` counts the compute blocks the decode kernel runs
+    over the slots, each attending its length plus the new token (an empty
+    slot one block); ``attn_blocks_grid`` what walking every whole table
+    would run. A block is 512 tokens, two to a 40-page table of 16."""
+    model = Model(tiny_config(d_model=256, num_heads=2, num_kv_heads=1))
+    params = model.init_params(jax.random.PRNGKey(0))
+    eng = Engine(model, params, EngineConfig(
+        page_size=16, num_pages=96, max_slots=3, max_pages_per_branch=40,
+        eos_id=1, sampling=SamplingParams(temperature=0.0)))
+    for prompt in ([2] * 511, [3] * 10):
+        blocks, logits, ssm = eng.prefill(prompt)
+        eng.spawn_branch(0, blocks, logits, ssm, len(prompt))
+    run = []
+    for _ in range(3):
+        before = eng.attn_blocks_run
+        eng.decode_step()
+        run.append(eng.attn_blocks_run - before)
+    # 512, 513, 514 tokens: 1, 2, 2 blocks; 11-13 tokens: 1; empty slot: 1
+    assert run == [3, 4, 4]
+    assert eng.attn_blocks_grid == 3 * 3 * 2

@@ -12,22 +12,77 @@ from repro.kernels.ssd_scan.ref import ssd_scan_ref
 from repro.models.mamba2 import ssd_chunked
 
 
-@pytest.mark.parametrize("b,qh,kvh,hd,ps,pps", [
-    (2, 4, 2, 64, 8, 4),
-    (3, 8, 8, 128, 16, 3),
-    (1, 8, 1, 256, 8, 5),
-    (4, 2, 2, 32, 4, 8),
+def _decode_tables(rng, lens, ps, pps, npages):
+    """Sentinel-padded block tables (entry ``npages`` past each row's
+    pages) over distinct pages below ``npages - 1``, so the page the
+    sentinel clamps to is nobody's; row 4 shares row 5's first two
+    pages, as siblings share a prompt."""
+    bt = np.full((len(lens), pps), npages, np.int32)
+    free = iter(rng.permutation(npages - 1))
+    for row, n in enumerate(lens):
+        for i in range(-(-n // ps)):
+            bt[row, i] = next(free)
+    bt[4, :2] = bt[5, :2]
+    return bt
+
+
+# the last two cases are qwen2-0.5b's GQA (group 7, head_dim 64) and
+# r1-distill-14b's (group 5, head_dim 128), where a compute block is 512
+# tokens, 32 pages of 16. Lengths of 1, one page, one past a
+# page, one block, one past a block, the full table (whose width the block
+# does not divide), and an empty slot, over sentinel-padded tables
+@pytest.mark.parametrize("b,qh,kvh,hd,ps,pps,lens", [
+    (2, 4, 2, 64, 8, 4, None),
+    (3, 8, 8, 128, 16, 3, None),
+    (1, 8, 1, 256, 8, 5, None),
+    (4, 2, 2, 32, 4, 8, None),
+    (7, 14, 2, 64, 16, 40, (1, 16, 17, 512, 513, 640, 0)),
+    (7, 10, 2, 128, 16, 36, (1, 16, 17, 512, 513, 576, 0)),
 ])
-def test_paged_attention_shapes(rng, b, qh, kvh, hd, ps, pps):
+def test_paged_attention_shapes(rng, b, qh, kvh, hd, ps, pps, lens):
     npages = b * pps + 2
     q = jnp.asarray(rng.normal(size=(b, qh, hd)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(kvh, npages, ps, hd)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(kvh, npages, ps, hd)), jnp.float32)
-    bt = jnp.asarray(rng.integers(0, npages, size=(b, pps)), jnp.int32)
-    lens = jnp.asarray(rng.integers(1, pps * ps + 1, size=(b,)), jnp.int32)
+    if lens is None:
+        bt = jnp.asarray(rng.integers(0, npages, size=(b, pps)), jnp.int32)
+        lens = rng.integers(1, pps * ps + 1, size=(b,))
+    else:
+        bt = jnp.asarray(_decode_tables(rng, lens, ps, pps, npages))
+    lens = jnp.asarray(lens, jnp.int32)
     out = paged_attention(q, k, v, bt, lens)
     ref = paged_attention_decode_ref(q, k, v, bt, lens)
-    np.testing.assert_allclose(out, ref, atol=2e-4)
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-4)
+    # an empty slot runs no block and writes zeros
+    np.testing.assert_array_equal(out[~live], 0.0)
+
+
+def test_paged_attention_ignores_poisoned_tail(rng):
+    """NaN in every pool slot past every row's length — the rest of a
+    row's last page, the pages that share its last compute block, and
+    the page sentinel entries clamp to — must not reach any output: the
+    output equals the reference's over the clean pool."""
+    b, qh, kvh, hd, ps, pps = 7, 14, 2, 64, 16, 40
+    lens = (1, 17, 300, 512, 513, 630, 0)
+    npages = b * pps + 2
+    q = jnp.asarray(rng.normal(size=(b, qh, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(kvh, npages, ps, hd)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(kvh, npages, ps, hd)), jnp.float32)
+    bt = _decode_tables(rng, lens, ps, pps, npages)
+    live = np.zeros((npages, ps), bool)
+    for row, n in enumerate(lens):
+        for t in range(n):
+            live[bt[row, t // ps], t % ps] = True
+    assert not live[npages - 1].any()
+    dead = jnp.asarray(~live)[None, :, :, None]
+    bt, lens = jnp.asarray(bt), jnp.asarray(lens, jnp.int32)
+    out = paged_attention(q, jnp.where(dead, jnp.nan, k),
+                          jnp.where(dead, jnp.nan, v), bt, lens)
+    ref = paged_attention_decode_ref(q, k, v, bt, lens)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(out[:-1], ref[:-1], atol=2e-4)
+    np.testing.assert_array_equal(out[-1], 0.0)
 
 
 @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-4),
@@ -487,8 +542,11 @@ def test_tree_decode_fork_alignment(rng):
 
 def test_tree_decode_single_branch_degenerate(rng):
     """A 1-member fork group and a fully ungrouped batch must both
-    reproduce the plain decode kernel bit-for-bit — the tree machinery
-    degenerates to per-branch streaming."""
+    reproduce the plain decode kernel — the tree machinery degenerates to
+    per-branch streaming. Ungrouped, a row within one page is one softmax
+    update in both kernels and must match bit-for-bit; a longer row only
+    to rounding, since the decode kernel merges a block of pages per
+    update and the tree's branch pass one page."""
     t = _tree_topology(rng, groups=[(2, [2 * 4 + 3])], singles=[9, 5],
                        qh=4, kvh=2, hd=32, ps=4)
     ker, _tref, pb_ker, _pb_ref = _tree_all_paths(t)
@@ -497,7 +555,12 @@ def test_tree_decode_single_branch_degenerate(rng):
     t2 = _tree_topology(rng, groups=[], singles=[13, 6, 2],
                         qh=4, kvh=2, hd=32, ps=4)
     ker2, _t2ref, pb_ker2, _ = _tree_all_paths(t2)
-    np.testing.assert_array_equal(np.asarray(ker2), np.asarray(pb_ker2))
+    ker2, pb_ker2 = np.asarray(ker2), np.asarray(pb_ker2)
+    one_page = np.asarray(t2["lengths"]) <= 4
+    assert one_page.any() and not one_page.all()
+    np.testing.assert_array_equal(ker2[one_page], pb_ker2[one_page])
+    np.testing.assert_allclose(ker2[~one_page], pb_ker2[~one_page],
+                               atol=1e-6)
 
 
 def test_tree_decode_poisoned_unshared_page_invariance(rng):

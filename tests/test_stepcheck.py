@@ -56,7 +56,7 @@ def test_unclamped_decode_kv_map_is_caught():
     assert verify_kernel_grid(kg, cases) == []
 
     broken = _unclamp(kg, ("k_pages", "v_pages"),
-                      lambda b, h, i, bt, ln: (h, bt[b, i], 0, 0))
+                      lambda b, i, bt, ln: (0, bt[b, i], 0, 0))
     caught = verify_kernel_grid(broken, cases)
     assert {f.rule for f in caught} == {"STEP007"}
     assert {f.symbol for f in caught} == {"k_pages", "v_pages"}
@@ -113,7 +113,12 @@ def test_lattice_grids_are_exhaustive_and_pinned():
     from repro.kernels import (flash_prefill_grid, paged_attention_grid,
                                paged_prefill_grid, ssd_scan_grid)
     kg = paged_attention_grid(3, 4, 8, 2, 16, 4, 6)
-    assert kg.grid == (3, 2, 6) and grid_exhaustive_points(kg) == 36
+    assert kg.grid == (3,) and kg.in_mappings[1].loop == (6,)
+    assert grid_exhaustive_points(kg) == 18
+    # 4-page blocks over a 10-entry table: the loop spans three blocks
+    kg = paged_attention_grid(3, 4, 8, 2, 16, 128, 10)
+    assert kg.in_mappings[2].loop == (12,)
+    assert grid_exhaustive_points(kg) == 36
     kg = paged_prefill_grid(8, 4, 8, 2, 16, 4, 6, block_q=4)
     assert kg.grid == (2, 2, 6) and grid_exhaustive_points(kg) == 24
     kg = flash_prefill_grid(2, 12, 4, 8, 2, block_q=8, block_k=8)
@@ -132,7 +137,7 @@ def test_lattice_covers_all_kernels_and_head_regimes():
         kv_counts = {kg.in_mappings[1].array_shape[0]
                      for kg, _ in pairs if kg.kernel == kernel}
         assert kv_counts == {1, 2, 4}, kernel
-    assert len(pairs) == 22
+    assert len(pairs) == 23
     for kg, cases in pairs:
         assert grid_exhaustive_points(kg) > 0 and cases
 

@@ -39,7 +39,7 @@ def self_test() -> int:
     import dataclasses
     unclamped = tuple(
         dataclasses.replace(
-            m, index_map=lambda b, h, i, bt, ln: (h, bt[b, i], 0, 0))
+            m, index_map=lambda b, i, bt, ln: (0, bt[b, i], 0, 0))
         if m.name in ("k_pages", "v_pages") else m
         for m in kg.in_mappings)
     broken = dataclasses.replace(kg, in_mappings=unclamped)

@@ -39,7 +39,8 @@ class ScalarCase:
 
 def verify_kernel_grid(kg, cases: Sequence[ScalarCase] = (ScalarCase("-"),),
                        max_findings_per_mapping: int = 3) -> List[Finding]:
-    """Evaluate every index map of ``kg`` over the full grid × cases.
+    """Evaluate every index map of ``kg`` over the full grid × cases, and
+    over the kernel's own loop for a mapping that has one.
 
     Returns one STEP007 finding per violating (mapping, case), capped at
     ``max_findings_per_mapping`` grid points each (one out-of-bounds
@@ -48,8 +49,9 @@ def verify_kernel_grid(kg, cases: Sequence[ScalarCase] = (ScalarCase("-"),),
     findings: List[Finding] = []
     for mapping in kg.mappings:
         reported = 0
+        extents = kg.grid + mapping.loop
         for case in cases:
-            for point in itertools.product(*(range(g) for g in kg.grid)):
+            for point in itertools.product(*(range(g) for g in extents)):
                 if reported >= max_findings_per_mapping:
                     break
                 try:
@@ -91,12 +93,16 @@ def _containment_violation(idx: Tuple[int, ...], block: Tuple[int, ...],
 
 
 def grid_exhaustive_points(kg) -> int:
-    """Number of grid points a full sweep visits (tests pin this so the
-    lattice cannot silently stop being exhaustive)."""
-    points = 1
-    for g in kg.grid:
-        points *= g
-    return points
+    """Number of points a full sweep visits for the mapping with the most:
+    the grid times that mapping's own loop (tests pin this so the lattice
+    cannot silently stop being exhaustive)."""
+    def points(extents):
+        n = 1
+        for g in extents:
+            n *= g
+        return n
+    return max((points(kg.grid + m.loop) for m in kg.mappings),
+               default=points(kg.grid))
 
 
 # --------------------------------------------------------------- lattice
@@ -187,7 +193,8 @@ def engine_lattice() -> List[Tuple[object, List[ScalarCase]]]:
     """The (KernelGrid, scalar cases) pairs ``python -m tools.stepcheck``
     proves in-bounds: all six kernels, swept over GQA (kv < heads), MQA
     (kv = 1) and MHA (kv = heads) head counts plus block-size variations
-    that exercise internal padding."""
+    that exercise internal padding and a decode block that does not
+    divide the table."""
     from repro.kernels import (flash_prefill_grid, paged_attention_grid,
                                paged_prefill_grid, paged_tree_branch_grid,
                                paged_tree_shared_grid, ssd_scan_grid)
@@ -214,6 +221,10 @@ def engine_lattice() -> List[Tuple[object, List[ScalarCase]]]:
             kg = flash_prefill_grid(2, s, 4, 8, kv_heads,
                                     block_q=8, block_k=8)
             out.append((kg, [ScalarCase("-")]))
+    # decode blocks of 4 pages of 128 tokens over a 10-entry table: the
+    # last block reaches past the table and re-reads its last entry
+    kg = paged_attention_grid(3, 4, 8, 2, num_pages, 128, 10)
+    out.append((kg, paged_attention_cases(num_pages, 128, 10, 3)))
     out.append((ssd_scan_grid(2, 16, 2, 8, 4, 8), [ScalarCase("-")]))
     return out
 
