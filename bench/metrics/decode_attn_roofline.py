@@ -18,6 +18,6 @@ def read(run):
     least = 0.0
     for s in steps:
         flops, nbytes = roofline.decode_attention_work(
-            run.model, s.tables[0], s.tables[1], run.page_size)
+            run.work, s.tables[0], s.tables[1], run.page_size)
         least += roofline.least_seconds(flops, nbytes, run.device_kind)
     return 100.0 * least / (kernel / 1e9)

@@ -15,6 +15,6 @@ def read(run):
     if not kernel or not lanes:
         return None
     least = sum(roofline.least_seconds(
-        *roofline.prefill_attention_work(run.model, pos0, n),
+        *roofline.prefill_attention_work(run.work, pos0, n),
         run.device_kind) for pos0, n in lanes)
     return 100.0 * least / (kernel / 1e9)

@@ -9,7 +9,7 @@ def read(run):
     steps = window_steps(run)
     if not steps:
         return None
-    flops = sum(roofline.step_model_flops(run.model, s.rows, s.ctx, s.lanes)
+    flops = sum(roofline.step_model_flops(run.work, s.rows, s.ctx, s.lanes)
                 for s in steps)
     peak = roofline.peaks(run.device_kind)["bf16_flops"]
     return 100.0 * flops / ((run.rec.t_close - run.rec.t_open) * peak)

@@ -22,7 +22,7 @@ import numpy as np
 from . import check, stats, trace as tracemod
 from .loadgen import LoadGen, Records, request_times
 from .proxy import EngineProxy, profiler_span
-from .spec import BENCH, ROOT, Cell
+from .spec import BENCH, ROOT, Cell, Work
 from .traffic import Traffic
 from .weights import make_weights
 
@@ -74,8 +74,9 @@ class Run:
     trace_ns: Optional[tuple] = None      # traced stretch in trace time
 
     @property
-    def model(self) -> dict:
-        return self.cell.config["model"]
+    def work(self) -> Work:
+        """What each layer holds, by kind (``spec.describe``)."""
+        return self.cell.work
 
     @property
     def page_size(self) -> int:
@@ -293,8 +294,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 def load_summary(run: Run) -> dict:
     """Requests sent, answered in the window, and outstanding (due and
     not finished) when the window opened and when it closed: a queue that
-    grows through the window means load above what the system sustains."""
+    grows through the window means load above what the system sustains.
+    ``busy_slots``: decode rows per window step, on average; under the
+    engine's slots, the load left the batch part empty."""
     rec = run.rec
+    steps = stats.window_steps(run)
 
     def outstanding(t):
         return sum(1 for r in run.requests if r["due"] <= t
@@ -303,7 +307,9 @@ def load_summary(run: Run) -> dict:
             "answered": sum(1 for r in run.requests if r["finish"]
                             is not None and rec.in_window(r["finish"])),
             "outstanding_open": outstanding(rec.t_open),
-            "outstanding_close": outstanding(rec.t_close)}
+            "outstanding_close": outstanding(rec.t_close),
+            "busy_slots": (sum(s.rows for s in steps) / len(steps)
+                           if steps else 0.0)}
 
 
 def _log_counts(run: Run, compiles: int, faults: dict, log) -> None:

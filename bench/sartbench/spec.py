@@ -8,11 +8,12 @@ files, never editing one.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
 from types import ModuleType
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
@@ -46,6 +47,107 @@ class Cell:
 
     def metrics_of(self, kind: str) -> List[Metric]:
         return [m for m in self.metrics if m.kind == kind]
+
+    @functools.cached_property
+    def work(self) -> "Work":
+        """What each layer of the configuration holds, by kind."""
+        return describe(self.config)
+
+
+# the widths each kind of layer group states, besides ``kind``/``layers``
+KINDS = {
+    "attention": ("heads", "kv_heads", "head_dim"),
+    "mlp": ("width", "gated"),
+    "moe": ("experts", "held", "per_token", "width", "shared_width",
+            "gated"),
+    "mamba2": ("heads", "head_dim", "state", "groups", "conv"),
+}
+
+
+@dataclasses.dataclass(frozen=True)
+class Work:
+    """What the layers of a configuration hold, by kind."""
+    d_model: int
+    vocab_size: int
+    tie_embeddings: bool
+    groups: Tuple[dict, ...]
+
+    def of(self, kind: str) -> List[dict]:
+        return [g for g in self.groups if g["kind"] == kind]
+
+
+def attention_widths(model: dict) -> tuple:
+    """(heads, kv_heads, head_dim) that ``model`` builds its K/V pool
+    from."""
+    h = model.get("num_heads")
+    return (h, model.get("num_kv_heads"),
+            model.get("head_dim") or (h and model["d_model"] // h))
+
+
+def uniform(model: dict) -> List[dict]:
+    """The groups of a model whose every layer is attention plus an MLP."""
+    L = model["num_layers"]
+    h, kv, hd = attention_widths(model)
+    return [{"kind": "attention", "layers": L, "heads": h, "kv_heads": kv,
+             "head_dim": hd},
+            {"kind": "mlp", "layers": L, "width": model["d_ff"],
+             "gated": bool(model.get("mlp_gated", True))}]
+
+
+def _check_group(i: int, g) -> dict:
+    if not isinstance(g, dict) or g.get("kind") not in KINDS:
+        kind = g.get("kind") if isinstance(g, dict) else g
+        raise SpecError(f"work[{i}]: unknown kind {kind!r}; known: "
+                        f"{sorted(KINDS)}")
+    need = ("kind", "layers") + KINDS[g["kind"]]
+    missing = [k for k in need if k not in g]
+    extra = sorted(set(g) - set(need))
+    if missing or extra:
+        raise SpecError(f"work[{i}] ({g['kind']}): missing {missing}, "
+                        f"not of this kind {extra}")
+    for k in need[1:]:
+        v = g[k]
+        if k == "gated":
+            ok = isinstance(v, bool)
+        else:
+            least = 0 if k == "shared_width" else 1
+            ok = isinstance(v, int) and not isinstance(v, bool) \
+                and v >= least
+        if not ok:
+            raise SpecError(f"work[{i}] ({g['kind']}): {k} = {v!r}")
+    if g["kind"] == "moe" and not (g["per_token"] <= g["experts"]
+                                   and g["held"] <= g["experts"]):
+        raise SpecError(f"work[{i}] (moe): per_token and held are at "
+                        f"most experts ({g['experts']})")
+    return dict(g)
+
+
+def _check_attention(i: int, g: dict, model: dict) -> None:
+    """An attention group states the widths of ``model``'s K/V pool, so
+    that the counts are of the pool that is timed."""
+    got = (g["heads"], g["kv_heads"], g["head_dim"])
+    want = attention_widths(model)
+    if got != want:
+        raise SpecError(f"work[{i}] (attention): heads, kv_heads, head_dim "
+                        f"{got} differ from model's {want}")
+
+
+def describe(config: dict) -> Work:
+    """The layer groups of a configuration file (module docstring of
+    ``roofline``): its ``work``, or the uniform reading of its ``model``
+    where it has none."""
+    model = config["model"]
+    groups = config.get("work")
+    if groups is None:
+        groups = uniform(model)
+    if not isinstance(groups, list) or not groups:
+        raise SpecError("work is a non-empty list of layer groups")
+    checked = tuple(_check_group(i, g) for i, g in enumerate(groups))
+    for i, g in enumerate(checked):
+        if g["kind"] == "attention":
+            _check_attention(i, g, model)
+    return Work(int(model["d_model"]), int(model["vocab_size"]),
+                bool(model.get("tie_embeddings", False)), checked)
 
 
 def _read_json(path: Path) -> dict:
@@ -107,5 +209,7 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
                             m.get("workloads"), load_reader(m["name"], bench))
             if metric.applies_to(workload):
                 metrics.append(metric)
-    return Cell(workload, int(w["chips"]), load_config(w["config"], bench),
+    cell = Cell(workload, int(w["chips"]), load_config(w["config"], bench),
                 load_traffic(w["traffic"], bench), metrics)
+    cell.work                      # a bad ``work`` fails before any run
+    return cell

@@ -122,6 +122,7 @@ def test_window_records_and_samples(sound):
     assert load["outstanding_close"] == sum(
         1 for r in run_.requests if r["due"] <= rec.t_close
         and (r["finish"] is None or r["finish"] > rec.t_close))
+    assert 0 < load["busy_slots"] <= cell.config["engine"]["max_slots"]
 
 
 def test_closed_loop_keeps_its_backlog():
