@@ -64,7 +64,11 @@ def setup_compile_cache() -> str:
 
 @dataclasses.dataclass
 class Run:
-    """What a metric's reader reads."""
+    """What a metric's reader reads: the window's records (each step's
+    shapes and the engine's counters that moved in it, ``Step.counts``),
+    the requests' host times, and with ``--trace 1`` the compact trace
+    (device operations, the benchmark's spans, the program's spans and
+    their stats under ``program``)."""
     cell: Cell
     device_kind: str
     rec: Records

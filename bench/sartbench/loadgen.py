@@ -12,6 +12,11 @@ the next step: in-flight requests are not drained.
 Every scheduler tick is mapped to the host time at which the step that
 produced it ended, so the scheduler's tick stamps (``first_service``,
 ``first_branch``, ``finish``) become host times.
+
+After every step the load generator reads the engine's cumulative
+counters (``engine_counters``) and keeps on the step's record what moved
+in it (``Step.counts``): a reader sums a counter over the steps it reads,
+so a counter the program adds reaches a reader file with no edit here.
 """
 from __future__ import annotations
 
@@ -19,8 +24,22 @@ import dataclasses
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+import numpy as np
+
 from .proxy import (EngineProxy, WindowClosed, lanes_run, no_span,
                     step_lanes)
+
+
+def engine_counters(engine) -> Dict[str, int]:
+    """The engine's cumulative counters by name: what ``engine.counters()``
+    returns where the engine has it, else every public attribute of the
+    engine that holds an integer (``rows_computed``, ``rows_useful``,
+    ``attn_blocks_run``, ...)."""
+    read = getattr(engine, "counters", None)
+    items = read().items() if callable(read) else (
+        (k, v) for k, v in vars(engine).items() if not k.startswith("_"))
+    return {k: int(v) for k, v in items
+            if isinstance(v, (int, np.integer)) and not isinstance(v, bool)}
 
 
 @dataclasses.dataclass
@@ -33,6 +52,8 @@ class Step:
     tokens: int                    # tokens the step produced
     used_pages: int                # KV pages in use after the step
     tables: Optional[tuple] = None  # (block tables, lengths) when traced
+    # the engine's counters that moved in the step, by how much
+    counts: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -46,6 +67,10 @@ class Records:
     due: Dict[int, float]          # request_id -> host time due
     late: List[float]              # submit time minus due time
     trace_span: Optional[Tuple[float, float]] = None  # profiled stretch
+    # the engine's counters after the last step that ended before the
+    # window opened, and after the last that ended in it
+    counters_open: Dict[str, int] = dataclasses.field(default_factory=dict)
+    counters_close: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def in_window(self, t: float) -> bool:
         return self.t_open <= t <= self.t_close
@@ -154,10 +179,18 @@ class LoadGen:
         if self.record_tables and self._tracing:
             tables = (eng._block_tables[active].copy(),
                       eng._lengths[active].copy())
+        counters, prev = engine_counters(eng), self._counters
+        self._counters = counters
+        if t1 < rec.t_open:
+            rec.counters_open = counters
+        if t1 <= rec.t_close:
+            rec.counters_close = counters
         rec.steps.append(Step(
             t0, t1, int(active.sum()), int(eng._lengths[active].sum())
             + int(active.sum()), lanes_run(self._pending_lanes), len(out),
-            eng.allocator.used_pages, tables))
+            eng.allocator.used_pages, tables,
+            {k: v - prev.get(k, 0) for k, v in counters.items()
+             if v != prev.get(k, 0)}))
         rec.tick_time[self.sch.clock + 1] = t1
         if self.span is not None:
             self._sched_span = self.span("scheduler")
@@ -170,6 +203,8 @@ class LoadGen:
                            t_close=t + warm_s + seconds, steps=[], itl=[],
                            tick_time={0: t}, due={}, late=[])
         rec = self.rec
+        self._counters: Dict[str, int] = engine_counters(self.proxy.inner)
+        rec.counters_open = rec.counters_close = self._counters
         try:
             while True:
                 now = time.perf_counter()

@@ -32,18 +32,26 @@ depth states its depth to the program once and its layers to the count by
 kind.
 
 Bytes are at the served type's itemsize (2, bf16). A step's weight bytes
-count the routed experts of an MoE group only as far as a step's record
-says it reached them; no record carries that yet (it waits for a
-per-expert counter from the program), so routed experts count zero and
-the step's bytes stay a lower bound.
+count the routed experts of an MoE group only as far as the step reached
+them, by the program's counter ``EXPERTS_REACHED`` (an engine counter, read
+by ``loadgen.engine_counters`` and kept per step on ``Step.counts``): the
+held experts that got at least one of the step's tokens, summed over the
+MoE layers of the step. Without that counter, or in a step where it did
+not move, routed experts count zero and the step's bytes stay a lower
+bound. The counter does not say how its sum splits between MoE groups, so
+a configuration with more than one is a ``SpecError`` where a step's
+record holds it (``experts_reached``).
 """
 from __future__ import annotations
 
-from typing import Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .spec import Work, uniform
+from .spec import SpecError, Work, uniform
+
+# the program's counter of routed experts reached in a step
+EXPERTS_REACHED = "moe.experts_reached"
 
 # Published peaks per chip, keyed by jax's ``device_kind``. Source: Google
 # Cloud documentation, "TPU v5e" (system architecture): 197 TFLOP/s bf16,
@@ -263,6 +271,24 @@ def step_bytes(w: Work, tables, lengths, lanes: Sequence[Tuple[int, int]],
         elif g["kind"] == "mamba2":
             total += g["layers"] * 2 * seqs * mamba2_state_bytes(g, itemsize)
     return total
+
+
+def experts_reached(w: Work, counts: Dict[str, int]) -> Optional[List[int]]:
+    """Routed experts reached per MoE group (``step_bytes``'s ``reached``)
+    from a step's counter changes; None without an MoE group or where
+    the step's record does not hold ``EXPERTS_REACHED``."""
+    moe = w.of("moe")
+    if not moe or EXPERTS_REACHED not in counts:
+        return None
+    if len(moe) > 1:
+        raise SpecError(f"{EXPERTS_REACHED} sums over {len(moe)} MoE "
+                        f"groups and cannot be split between them")
+    reached, g = counts[EXPERTS_REACHED], moe[0]
+    if not 0 <= reached <= g["layers"] * g["held"]:
+        raise ValueError(f"{EXPERTS_REACHED} = {reached} in one step; "
+                         f"{g['layers']} MoE layers hold {g['held']} "
+                         f"experts each")
+    return [reached]
 
 
 def least_seconds(flops: float, nbytes: float, device_kind: str) -> float:

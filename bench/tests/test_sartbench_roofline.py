@@ -11,7 +11,7 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from sartbench import roofline  # noqa: E402
+from sartbench import roofline, trace  # noqa: E402
 from sartbench.spec import (SpecError, describe, load_config,  # noqa: E402
                             load_reader)
 
@@ -115,7 +115,6 @@ def recorded_run(conf):
     sharing their prompt pages, in 320-page tables) and one 64-row chunk
     lane at 128, drawn from a fixed seed."""
     import json
-    from sartbench import trace
     from sartbench.cell import Run
     from sartbench.loadgen import Records, Step
     from sartbench.spec import Cell
@@ -195,10 +194,53 @@ def test_readers_on_recorded_steps_read_as_before(name):
     assert 0 < readings[0] < 100
 
 
+# every per-layer reader BENCHMARK.json named before the program's spans
+# and counters reached the records, as the parent read it on
+# recorded_run: pinned bit for bit (r1's kv_pages_used reads the qwen2
+# steps' pages over r1's smaller pool)
+PARENT = {
+    "qwen2-0.5b": {"sched_host_ms_per_step": 0.2991833333333333,
+                   "engine_host_ms_per_step": 6.289052666666667,
+                   "step_device_ms": 190.98097666666666,
+                   "step_mfu": 0.005222140517360406,
+                   "step_roofline": 1.2594171736577071,
+                   "decode_attn_roofline": 1.0349150858765825,
+                   "prefill_attn_roofline": 0.45061168846984706,
+                   "kv_pages_used": 48.857421875,
+                   "device_idle_share": 3.3346470895318503},
+    "r1-distill-14b-l8": {"sched_host_ms_per_step": 0.2991833333333333,
+                          "engine_host_ms_per_step": 6.289052666666667,
+                          "step_device_ms": 190.98097666666666,
+                          "step_mfu": 0.029818270104906938,
+                          "step_roofline": 5.486039211150366,
+                          "decode_attn_roofline": 2.7564622203859757,
+                          "prefill_attn_roofline": 0.9613049354023403,
+                          "kv_pages_used": 162.85807291666666,
+                          "device_idle_share": 3.3346470895318503},
+}
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_every_reader_reads_the_parents_float(name):
+    """On the recorded trace, with its ``program`` spans and without
+    them, each reader the parent had reads the parent's float; the two
+    readers of the program's spans and counters read theirs or None."""
+    run = recorded_run(load_config(name))
+    bare = recorded_run(load_config(name))
+    bare.trace = {k: v for k, v in bare.trace.items() if k != "program"}
+    for r in (run, bare):
+        got = {m: load_reader(m).read(r) for m in PARENT[name]}
+        assert got == PARENT[name]
+        # the recorded steps carry no counters
+        assert load_reader("padded_row_share").read(r) is None
+    busy = load_reader("engine_host_busy_ms_per_step")
+    assert busy.read(bare) is None
+    assert 0 < busy.read(run) < PARENT[name]["engine_host_ms_per_step"]
+
+
 def test_step_roofline_by_hand():
     """The qwen2 run: the mean least time of the three steps over the
     device time inside the three ``engine.decode_step`` spans."""
-    from sartbench import trace
     run = recorded_run(load_config("qwen2-0.5b"))
     least = [roofline.least_seconds(
         roofline.step_model_flops(run.work, s.rows, s.ctx, s.lanes),
@@ -321,3 +363,60 @@ def test_a_bad_work_is_refused(group, words):
     conf = {"model": QWEN, "work": [group]}
     with pytest.raises(SpecError, match=re.escape(words)):
         describe(conf)
+
+
+# --- routed experts reached, by the program's counter ----------------------
+
+
+def moe_run(config=NEMOTRON_L14):
+    return recorded_run({**config, "engine": {"page_size": 16,
+                                              "num_pages": 10240}})
+
+
+def test_routed_expert_bytes_follow_the_counter():
+    """A step whose record holds ``moe.experts_reached`` counts that many
+    routed experts' weights; without it, or with other counters alone, the
+    step reads as before."""
+    reader = load_reader("step_roofline")
+    run = moe_run()
+    w = run.work
+    before = reader.read(run)
+    for s in run.rec.steps:
+        s.counts = {"rows_computed": 112, "rows_useful": 110}
+    assert reader.read(run) == before
+    reached = [40, 61, 96]              # 6 MoE layers x 16 held = 96 at most
+    for s, n in zip(run.rec.steps, reached):
+        s.counts = {roofline.EXPERTS_REACHED: n}
+    spans = [e for e in run.trace["host"] if e[0] == "engine.decode_step"]
+    busy = trace.device_ns_within(run.trace, spans) / 1e9
+    least = [roofline.least_seconds(
+        roofline.step_model_flops(w, s.rows, s.ctx, s.lanes),
+        roofline.step_bytes(w, *s.tables, s.lanes, 16, reached=[n]), KIND)
+        for s, n in zip(run.rec.steps, reached)]
+    got = reader.read(run)
+    assert got == pytest.approx(100 * sum(least) / busy, rel=1e-12)
+    assert got > before
+    # each routed expert reached: two 2,688 x 1,856 matrices, bf16
+    s = run.rec.steps[0]
+    assert roofline.step_bytes(w, *s.tables, s.lanes, 16,
+                               roofline.experts_reached(w, s.counts)) == \
+        roofline.step_bytes(w, *s.tables, s.lanes, 16) + \
+        40 * 2 * 2688 * 1856 * 2
+
+
+def test_experts_reached_by_configuration():
+    counts = {roofline.EXPERTS_REACHED: 12}
+    # no MoE group: the counter is not read
+    assert roofline.experts_reached(QWEN_WORK, counts) is None
+    nemo = describe(NEMOTRON_L14)
+    assert roofline.experts_reached(nemo, counts) == [12]
+    assert roofline.experts_reached(nemo, {}) is None
+    with pytest.raises(ValueError, match="97 in one step"):
+        roofline.experts_reached(nemo, {roofline.EXPERTS_REACHED: 97})
+    # two MoE groups: the sum cannot be split between them
+    moe = next(g for g in NEMOTRON_L14["work"] if g["kind"] == "moe")
+    two = describe({**NEMOTRON_L14,
+                    "work": NEMOTRON_L14["work"] + [dict(moe, layers=1)]})
+    assert roofline.experts_reached(two, {}) is None
+    with pytest.raises(SpecError, match="2 MoE groups"):
+        roofline.experts_reached(two, counts)

@@ -1,11 +1,15 @@
 """A whole run at smoke widths on the CPU: the proxy drives Scheduler and
 Engine through a short window; the counts, the load summary and the
-tick-to-time map hold; the check passes the sound program and fails each
-fault the serving cells can have; the measuring path refuses the CPU."""
+tick-to-time map hold; the engine's counters reach each step's record; a
+span and a counter the program adds reach reader files of their own; the
+check passes the sound program and fails each fault the serving cells can
+have; the measuring path refuses the CPU."""
 import io
+import json
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
@@ -123,6 +127,114 @@ def test_window_records_and_samples(sound):
         1 for r in run_.requests if r["due"] <= rec.t_close
         and (r["finish"] is None or r["finish"] > rec.t_close))
     assert 0 < load["busy_slots"] <= cell.config["engine"]["max_slots"]
+
+
+def test_counter_changes_sum_to_the_window(sound):
+    """Each step keeps what moved in the engine's counters; over the
+    window's steps the changes sum to the counters' change from the
+    window's open to its close."""
+    _, _, rec, _ = sound
+    steps = [s for s in rec.steps if rec.in_window(s.t1)]
+    assert {"rows_computed", "rows_useful", "attn_blocks_run",
+            "attn_blocks_grid"} <= set(rec.counters_close)
+    for name, close in rec.counters_close.items():
+        assert sum(s.counts.get(name, 0) for s in steps) == \
+            close - rec.counters_open.get(name, 0), name
+    # every step runs its slots' rows, and every key kept moved
+    assert all(s.counts["rows_computed"] > 0 for s in rec.steps)
+    assert all(v for s in rec.steps for v in s.counts.values())
+    run_ = SimpleNamespace(rec=rec)
+    share = load_reader("padded_row_share").read(run_)
+    computed = sum(s.counts["rows_computed"] for s in steps)
+    useful = sum(s.counts.get("rows_useful", 0) for s in steps)
+    assert share == 100.0 * (1.0 - useful / computed)
+    assert 0 < share < 100
+
+
+PROBE_MODULE = """
+from repro.tracing import span
+
+
+def mark(n):
+    with span("probe.span", n=n):
+        pass
+"""
+# readers a later PR would add as files of their own
+PROBE_READERS = {
+    "probe_span_n": """
+from sartbench import trace
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    spans = trace.program(run.trace, *run.trace_ns)
+    if spans is None:
+        return None
+    return float(sum(e[3]["n"] for e in spans if e[0] == "probe.span"))
+""",
+    "probe_count": """
+from sartbench.stats import counter_sum
+
+
+def read(run):
+    n = counter_sum(run, run.traced_steps(), "probe_count")
+    return None if n is None else float(n)
+""",
+}
+
+
+def test_a_new_span_and_counter_reach_new_reader_files(tmp_path,
+                                                       monkeypatch):
+    """A module the program adds writes a span through
+    ``repro.tracing.span``, and the engine gains a counter; two reader
+    files in a new ``bench/metrics/`` read them from a traced run, with
+    no edit to the harness."""
+    import importlib
+    from sartbench import trace
+    src = tmp_path / "src" / "repro"
+    src.mkdir(parents=True)
+    (src / "probe_ext.py").write_text(PROBE_MODULE)
+    metrics = tmp_path / "bench" / "metrics"
+    metrics.mkdir(parents=True)
+    for name, text in PROBE_READERS.items():
+        (metrics / f"{name}.py").write_text(text)
+    monkeypatch.syspath_prepend(str(tmp_path / "src"))
+    monkeypatch.setattr(cellmod, "OUT", tmp_path / "out")
+    probe = importlib.import_module("repro.probe_ext")
+    try:
+        assert "probe.span" in trace.program_span_names()
+
+        def fault(engine):
+            engine.probe_count = 0
+            step = engine.decode_step
+
+            def decode_step():
+                out = step()
+                engine.probe_count += 1
+                probe.mark(2)
+                return out
+            engine.decode_step = decode_step
+
+        base = smoke_cell()
+        cell = Cell(base.name, 1, base.config, base.traffic, [
+            Metric(n, "x", "higher", "per_layer", None,
+                   load_reader(n, tmp_path / "bench"))
+            for n in PROBE_READERS])
+        res = cellmod.run_cell(cell, SEED, 2.0, True, time.perf_counter(),
+                               device_check=False, fault=fault,
+                               log=io.StringIO())
+    finally:
+        sys.modules.pop("repro.probe_ext", None)
+    assert res["correct"]
+    got = {k: v["value"] for k, v in res["metrics"].items()}
+    compact = json.loads((tmp_path / "out" /
+                          f"{cell.name}.{SEED}.trace.json").read_text())
+    steps = [e for e in compact["host"] if e[0] == "engine.decode_step"]
+    # one probe span of n = 2 and one count in each traced step
+    assert got == {"probe_count": float(len(steps)),
+                   "probe_span_n": 2.0 * len(steps)}
+    assert len(steps) > 0
 
 
 def test_closed_loop_keeps_its_backlog():
